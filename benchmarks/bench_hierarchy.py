@@ -96,7 +96,8 @@ _CHILD = textwrap.dedent("""
     wall = (time.time() - t0) / reps
     mesh_axes = tuple(zip(mesh.axis_names, mesh.devices.shape))
     mix_mode = topology.resolve_mix_plan(spec, mesh_axes).mode
-    print(json.dumps({"layout": layout, "devices": n_dev,
+    print(json.dumps({"platform": jax.devices()[0].platform,
+                      "layout": layout, "devices": n_dev,
                       "n_clusters": n_clusters, "mix_mode": mix_mode,
                       "rounds_per_s": n_rounds / wall, "wall_s": wall,
                       "model_bytes": model_bytes,
@@ -112,6 +113,9 @@ def bench(n_clusters: int = 2, n_dev: int = 8, n_rounds: int = 16,
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # host placeholder devices only: on a chip host the parent may hold the
+    # chip, and a child on one TPU device would not be this bench's mesh
+    env["JAX_PLATFORMS"] = "cpu"
     out = {}
     for layout in ("flat", "cluster"):
         proc = subprocess.run(
@@ -120,13 +124,14 @@ def bench(n_clusters: int = 2, n_dev: int = 8, n_rounds: int = 16,
              str(tau), str(reps)],
             capture_output=True, text=True, env=env, timeout=900)
         if proc.returncode != 0:
-            print(f"# hierarchy {layout} FAILED: {proc.stderr[-500:]}")
-            continue
+            raise RuntimeError(f"hierarchy {layout} child failed: "
+                               f"{proc.stderr[-500:]}")
         res = json.loads(proc.stdout.strip().splitlines()[-1])
         out[layout] = res
         common.csv_line(
             f"hierarchy_{layout}_G{n_clusters}_D{n_dev}_C{n_clients}",
             res["wall_s"] / n_rounds * 1e6,
+            f"platform={res['platform']};"
             f"rounds_per_s={res['rounds_per_s']:.1f};"
             f"mix_bytes={res['est_mix_bytes_per_round']:.0f}")
     if "flat" in out and "cluster" in out:

@@ -122,7 +122,8 @@ _CHILD = textwrap.dedent("""
     for _ in range(reps):
         state, hist, ledger = run()
     wall = (time.time() - t0) / reps
-    print(json.dumps({"devices": n_dev, "mode": mode,
+    print(json.dumps({"platform": jax.devices()[0].platform,
+                      "devices": n_dev, "mode": mode,
                       "rounds_per_s": n_rounds / wall, "wall_s": wall,
                       "model_bytes": model_bytes,
                       "est_mix_bytes_per_round": mix_bytes,
@@ -138,6 +139,9 @@ def bench(device_counts=(1, 2, 4, 8), n_rounds: int = 16, n_clients: int = 16,
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # host placeholder devices only: on a chip host the parent may hold the
+    # chip, and a child on one TPU device would not be this bench's mesh
+    env["JAX_PLATFORMS"] = "cpu"
     out = {}
     for d in device_counts:
         if n_clients % d:
@@ -151,11 +155,12 @@ def bench(device_counts=(1, 2, 4, 8), n_rounds: int = 16, n_clients: int = 16,
                  mode],
                 capture_output=True, text=True, env=env, timeout=900)
             if proc.returncode != 0:
-                print(f"# devices={d} {mode} FAILED: {proc.stderr[-500:]}")
-                continue
+                raise RuntimeError(f"devices={d} {mode} child failed: "
+                                   f"{proc.stderr[-500:]}")
             res = json.loads(proc.stdout.strip().splitlines()[-1])
             modes[mode] = res
-            note = f"rounds_per_s={res['rounds_per_s']:.1f}"
+            note = (f"platform={res['platform']};"
+                    f"rounds_per_s={res['rounds_per_s']:.1f}")
             if res.get("interpret"):
                 note += ";interpret=True"
             common.csv_line(

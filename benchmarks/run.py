@@ -1,7 +1,10 @@
 """Benchmark harness — one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV lines and writes the full structured
-results to experiments/bench_results.json.
+results to experiments/bench_results.json. A phase that fails ends the run
+with a non-zero exit. Everything runs in this process except the
+``multidevice`` and ``hierarchy`` children, which fan the host CPU out into
+placeholder devices and never touch an accelerator.
 
   PYTHONPATH=src python -m benchmarks.run            # everything
   PYTHONPATH=src python -m benchmarks.run --only fig3,table6
@@ -22,6 +25,7 @@ from benchmarks import (bench_cohort, bench_hierarchy, bench_kernels,  # noqa: E
                         bench_multidevice, bench_robust, bench_rounds,
                         bench_schedules, bench_topology, paper_tables,
                         roofline)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments",
                    "bench_results.json")
@@ -38,6 +42,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
     datasets = ["mnist"] if args.fast else ["mnist", "fashion"]
 
     benches = {
@@ -58,12 +63,7 @@ def main() -> None:
         if only and name not in only:
             continue
         for ds in datasets:
-            try:
-                results[f"{name}_{ds}"] = fn(ds)
-            except Exception as e:  # keep the harness running
-                print(f"{name}_{ds},0,ERROR:{type(e).__name__}:{e}",
-                      flush=True)
-                results[f"{name}_{ds}"] = {"error": str(e)}
+            results[f"{name}_{ds}"] = fn(ds)
     if only is None or "kernels" in only:
         results["kernels"] = bench_kernels.run()
     if only is None or "rounds" in only:

@@ -87,6 +87,12 @@ import jax.numpy as jnp
 
 AxisName = Union[str, Tuple[str, ...], None]
 
+# Every mix contraction runs at full f32. A TPU's default for an f32 dot is
+# one bf16 pass, which would round each aggregated parameter to 8 mantissa
+# bits per round and fork psum from gather by 2^-9 relative; XLA:CPU
+# computes f32 dots in f32 either way.
+MIX_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def fedavg(params, weights: Optional[jnp.ndarray] = None):
     """Mean (optionally weighted by |D_i|) over leading client axis C,
@@ -103,7 +109,8 @@ def fedavg(params, weights: Optional[jnp.ndarray] = None):
             agg = jnp.mean(leaf.astype(jnp.float32), axis=0)
         else:
             w = (weights / jnp.sum(weights)).astype(jnp.float32)
-            agg = jnp.tensordot(w, leaf.astype(jnp.float32), axes=(0, 0))
+            agg = jnp.tensordot(w, leaf.astype(jnp.float32), axes=(0, 0),
+                                precision=MIX_PRECISION)
         return jnp.broadcast_to(agg, leaf.shape).astype(leaf.dtype)
 
     return jax.tree.map(one, params)
@@ -136,7 +143,8 @@ def mix(params, W: jnp.ndarray, weights: Optional[jnp.ndarray] = None):
 
     def one(leaf):
         flat = leaf.astype(jnp.float32).reshape((leaf.shape[0], -1))
-        return (W @ flat).reshape(leaf.shape).astype(leaf.dtype)
+        return jnp.matmul(W, flat, precision=MIX_PRECISION).reshape(
+            leaf.shape).astype(leaf.dtype)
 
     return jax.tree.map(one, params)
 
@@ -148,7 +156,8 @@ def aggregate_once(params, weights: Optional[jnp.ndarray] = None):
         if weights is None:
             return jnp.mean(leaf.astype(jnp.float32), axis=0).astype(leaf.dtype)
         w = (weights / jnp.sum(weights)).astype(jnp.float32)
-        return jnp.tensordot(w, leaf.astype(jnp.float32), axes=(0, 0)).astype(leaf.dtype)
+        return jnp.tensordot(w, leaf.astype(jnp.float32), axes=(0, 0),
+                             precision=MIX_PRECISION).astype(leaf.dtype)
 
     return jax.tree.map(one, params)
 
@@ -536,7 +545,8 @@ def mix_cluster(params, n_clusters: int, inter_weight: float,
 
     def combine(m, prv, nxt):
         # one dot_general, never scaled adds: see the docstring's FMA note
-        return jnp.tensordot(w_row, jnp.stack([m, prv, nxt], axis=0), axes=1)
+        return jnp.tensordot(w_row, jnp.stack([m, prv, nxt], axis=0), axes=1,
+                             precision=MIX_PRECISION)
 
     def dense(tree):
         def one(leaf):
@@ -681,7 +691,7 @@ def robust_geomedian(full_tree, n_iters: int = 8, eps: float = 1e-6):
         d = jnp.sqrt(jnp.sum((flat - y[None]) ** 2, axis=1))   # [C]
         w = 1.0 / jnp.maximum(d, jnp.float32(eps))
         w = w / jnp.sum(w)
-        return jnp.tensordot(w, flat, axes=(0, 0))
+        return jnp.tensordot(w, flat, axes=(0, 0), precision=MIX_PRECISION)
 
     y = jax.lax.fori_loop(0, int(n_iters), body, jnp.mean(flat, axis=0))
 
@@ -783,7 +793,8 @@ def mix_psum(params, weights: Optional[jnp.ndarray] = None, *,
         if weights is None:
             part = jnp.sum(x, axis=0)
         else:
-            part = jnp.tensordot(w_local, x, axes=(0, 0))
+            part = jnp.tensordot(w_local, x, axes=(0, 0),
+                                 precision=MIX_PRECISION)
         if axis_name is not None:
             part = jax.lax.psum(part, axis_name)
         if weights is None:
@@ -837,7 +848,8 @@ def mix_psum_dense(params, W: jnp.ndarray,
             from repro.kernels.fedavg.kernel import mix_rows_flat
             part = mix_rows_flat(w_cols, flat, interpret=interpret)
         else:
-            part = w_cols @ flat                   # [C, F] partial products
+            part = jnp.matmul(w_cols, flat,       # [C, F] partial products
+                              precision=MIX_PRECISION)
         full = jax.lax.psum(part, axis_name)
         mine = jax.lax.dynamic_slice_in_dim(full, idx * local, local, axis=0)
         return mine.reshape(leaf.shape).astype(leaf.dtype)

@@ -19,9 +19,9 @@ Two multi-round driver paths share the single-round engine:
 
   * ``run_blade_fl_scan`` — the compiled path. All K integrated rounds run
     inside one ``jax.jit(lax.scan)``; the ``RoundState`` carry (params, PRNG
-    key, round counter, prev-hash) never leaves the device (donated on
-    accelerator backends), per-round metrics and block-header fields come
-    back stacked ``[K]``, and the host sees exactly one end-of-run transfer.
+    key, round counter, prev-hash) never leaves the device (donated),
+    per-round metrics and block-header fields come back stacked ``[K]``,
+    and the host sees exactly one end-of-run transfer.
     ``chain.ledger_from_scan`` then replays the stacked headers through the
     validating ledger, so Steps 2-5 blockchain semantics are preserved
     bit-for-bit against the Python loop. Requires the batch to be a static
@@ -902,19 +902,17 @@ def _scan_runner(loss_fn: LossFn, spec: RoundSpec, n_rounds: int,
                             length=n_rounds)
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
-
         state_specs = RoundState(params=plan.client_spec(), key=P(),
                                  round_idx=P(), prev_hash=P())
-        run = shard_map(run, mesh=mesh,
-                        in_specs=(state_specs, plan.batch_spec(stacked)),
-                        out_specs=(state_specs, P()),
-                        check_rep=False)
+        run = jax.shard_map(run, mesh=mesh,
+                            in_specs=(state_specs, plan.batch_spec(stacked)),
+                            out_specs=(state_specs, P()),
+                            check_vma=False)
 
-    # Donate the carry so params never hold two live copies on accelerator
-    # backends; CPU has no donation support and would only warn.
-    donate = (0,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(run, donate_argnums=donate)
+    # Donate the carry so params never hold two live copies on the device.
+    # Every backend donates (XLA:CPU too), so the tests exercise the same
+    # buffer lifetimes the chip runs.
+    return jax.jit(run, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=16)
@@ -964,7 +962,9 @@ def run_blade_fl_scan(loss_fn: LossFn, spec: RoundSpec, params_single, batch,
         plan = plans_lib.scan_carry_plan(mesh, spec.n_clients)
     runner = _scan_runner(loss_fn, spec, int(n_rounds), bool(stacked),
                           mesh, plan)
-    state = init_state(params_single, key, spec.n_clients)
+    # the runner donates the carry: copy the caller's key into it so the
+    # caller's own key stays alive
+    state = init_state(params_single, key.copy(), spec.n_clients)
     state, stacked_metrics = runner(state, batch)
     host = jax.device_get(stacked_metrics)   # the one host transfer
     # the engine emits per-client losses [K, C]; the scalar means are
@@ -1132,8 +1132,6 @@ def _cohort_round_runner(loss_fn: LossFn, spec: RoundSpec,
     device layout at all."""
     if mesh is None:
         return _round_runner(loss_fn, spec, n_rounds)
-    from jax.experimental.shard_map import shard_map
-
     round_fn = make_integrated_round(loss_fn, spec,
                                      axis_name=plan.client_axes,
                                      n_shards=plan.n_shards,
@@ -1141,10 +1139,10 @@ def _cohort_round_runner(loss_fn: LossFn, spec: RoundSpec,
                                      axis_sizes=plan.axis_sizes)
     state_specs = RoundState(params=plan.client_spec(), key=P(),
                              round_idx=P(), prev_hash=P())
-    fn = shard_map(round_fn, mesh=mesh,
-                   in_specs=(state_specs, plan.batch_spec(False)),
-                   out_specs=(state_specs, P()),
-                   check_rep=False)
+    fn = jax.shard_map(round_fn, mesh=mesh,
+                       in_specs=(state_specs, plan.batch_spec(False)),
+                       out_specs=(state_specs, P()),
+                       check_vma=False)
     return jax.jit(fn)
 
 

@@ -21,9 +21,11 @@ from jax.experimental import pallas as pl
 
 def _fedavg_kernel(x_ref, w_ref, noise_ref, o_ref, *, with_noise: bool):
     x = x_ref[...].astype(jnp.float32)            # [C, bn]
-    w = w_ref[...].astype(jnp.float32)            # [C]
-    agg = jnp.einsum("c,cn->n", w, x)             # weighted mean (w sums to 1)
-    out = jnp.broadcast_to(agg[None, :], x.shape)
+    w = w_ref[...].astype(jnp.float32)            # [C, 1]
+    # weighted mean (w sums to 1) as a sublane reduce: Mosaic has no
+    # vector-matrix dot form for a rank-1 weight operand
+    agg = jnp.sum(w * x, axis=0, keepdims=True)   # [1, bn]
+    out = jnp.broadcast_to(agg, x.shape)
     if with_noise:
         out = out + noise_ref[...].astype(jnp.float32)
     o_ref[...] = out.astype(o_ref.dtype)
@@ -53,20 +55,21 @@ def fedavg_flat(x: jnp.ndarray, weights: jnp.ndarray,
         grid=(npad // block_n,),
         in_specs=[
             pl.BlockSpec((c, block_n), lambda i: (0, i)),
-            pl.BlockSpec((c,), lambda i: (0,)),
+            pl.BlockSpec((c, 1), lambda i: (0, 0)),
             noise_spec,
         ],
         out_specs=pl.BlockSpec((c, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((c, npad), x.dtype),
         interpret=interpret,
-    )(x, weights, noise)
+    )(x, jnp.reshape(weights, (c, 1)), noise)
     return out[:, :n]
 
 
 def _mix_rows_kernel(w_ref, x_ref, o_ref):
     w = w_ref[...].astype(jnp.float32)            # [R, K]
     x = x_ref[...].astype(jnp.float32)            # [K, bn]
-    o_ref[...] = jnp.dot(w, x,
+    # full f32, the precision of the engine's jnp mix (aggregation.py)
+    o_ref[...] = jnp.dot(w, x, precision=jax.lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -120,8 +123,10 @@ def _digest_div_kernel(x_ref, s_ref, r_ref):
     # column means over the (fully resident) client axis; zero-padded tail
     # columns contribute 0 to both outputs, so no mask is needed
     mean = jnp.sum(x, axis=0, keepdims=True) / np.float32(c)
-    s_ref[0] = s_ref[0] + jnp.sum(x)
-    r_ref[...] = r_ref[...] + jnp.sum((x - mean) ** 2, axis=1)
+    # both accumulators stay 2-D vectors: Mosaic stores no scalars to VMEM
+    s_ref[...] = s_ref[...] + jnp.sum(jnp.sum(x, axis=1, keepdims=True),
+                                      axis=0, keepdims=True)
+    r_ref[...] = r_ref[...] + jnp.sum((x - mean) ** 2, axis=1, keepdims=True)
 
 
 def digest_div_flat(x: jnp.ndarray, *, block_n: int = 2048,
@@ -144,10 +149,10 @@ def digest_div_flat(x: jnp.ndarray, *, block_n: int = 2048,
         _digest_div_kernel,
         grid=(x.shape[1] // block_n,),
         in_specs=[pl.BlockSpec((c, block_n), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((1,), lambda i: (0,)),
-                   pl.BlockSpec((c,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((1,), jnp.float32),
-                   jax.ShapeDtypeStruct((c,), jnp.float32)],
+        out_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
+                   pl.BlockSpec((c, 1), lambda i: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((c, 1), jnp.float32)],
         interpret=interpret,
     )(x)
-    return s[0], r
+    return s[0, 0], r[:, 0]

@@ -2,11 +2,14 @@
 
 Runs real integrated rounds (training + lazy + mining + chain) either:
   * paper-scale: --arch mlp  — the §7 substrate (MLP, synthetic non-IID
-    MNIST proxy, N=20 clients) on host devices; used by benchmarks/examples;
+    MNIST proxy, N=20 clients); used by benchmarks/examples/chip_smoke.py;
+  * cohort-scale: --arch mlp --enrolled N --cohort A — a cohort of A
+    clients per round out of N enrolled;
   * arch-scale: --arch <assigned id> --smoke — reduced config of the same
-    family, a few clients, synthetic token streams (CPU-runnable);
-  * mesh-scale: add --mesh to place the step on a (sub)mesh with the same
-    shardings the dry-run proves out.
+    family, a few clients, synthetic token streams (CPU-runnable).
+
+Each ``run_*`` function returns a :class:`RunOutput`; ``main`` prints its
+``result`` as JSON.
 
 Example:
   PYTHONPATH=src python -m repro.launch.train --arch mlp --rounds 10 --k 5
@@ -24,17 +27,29 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Any, List, NamedTuple, Optional
 
 import jax
 
 from repro.configs import BladeConfig, ShapeConfig, get_smoke_arch
-from repro.core import allocation, attacks, rounds, spectral, topology
+from repro.core import allocation, attacks, chain, rounds, spectral, topology
 from repro.data.pipeline import CohortDataSource, FLDataSource, LMDataSource
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_client_mesh, make_cluster_mesh
 from repro.models import registry
 from repro.models.mlp import init_mlp, mlp_loss
 from repro.sharding import plans
 from repro.training.metrics import MetricLogger
+
+
+class RunOutput(NamedTuple):
+    """What one ``run_*`` call produced."""
+    result: dict                # the JSON summary ``main`` prints
+    spec: rounds.RoundSpec
+    state: Any                  # final RoundState (cohort: PopulationStore)
+    history: List[dict]         # per-round metrics
+    ledger: chain.Ledger
+    batch: Any = None           # the static batch the scan ran over
 
 
 def spectral_fields(spec: rounds.RoundSpec, run_key, n_rounds: int) -> dict:
@@ -66,7 +81,7 @@ def adversary_fields(args) -> dict:
     return out
 
 
-def run_mlp(args) -> dict:
+def run_mlp(args) -> RunOutput:
     blade = BladeConfig(n_clients=args.clients, n_lazy=args.lazy,
                         sigma2=args.sigma2, t_sum=args.t_sum,
                         alpha=args.alpha, beta=args.beta, eta=args.eta,
@@ -96,12 +111,14 @@ def run_mlp(args) -> dict:
         mesh = make_client_mesh(args.devices) if args.devices else None
         plan = None
     run_key = jax.random.fold_in(key, 2)
-    t0 = time.time()
+    batch = src.static_batch()
+    t0 = time.perf_counter()
     # static batch -> compiled scan engine (K rounds, one dispatch);
     # --devices shards the client axis of the whole scan over the mesh
     state, hist, ledger = rounds.run_blade_fl(
-        mlp_loss, spec, params, src.static_batch(), run_key,
-        blade.K, mesh=mesh, plan=plan)
+        mlp_loss, spec, params, batch, run_key, blade.K, mesh=mesh,
+        plan=plan)
+    wall_s = _blocked_seconds(state, t0)
     # final eval on held-out data with the aggregated model
     from repro.core.aggregation import aggregate_once
     final = aggregate_once(state.params)
@@ -116,14 +133,13 @@ def run_mlp(args) -> dict:
         "devices": mesh.devices.size if mesh is not None else 1,
         "fast_allreduce": spec.fast_allreduce,
         "dispatch": dict(rounds.LAST_DISPATCH),
-        "wall_s": time.time() - t0,
+        "wall_s": wall_s,
         **spectral_fields(spec, run_key, blade.K),
     }
-    print(json.dumps(result, indent=1))
-    return result
+    return RunOutput(result, spec, state, hist, ledger, batch)
 
 
-def run_cohort(args) -> dict:
+def run_cohort(args) -> RunOutput:
     """Cohort-sampled population run: ``--enrolled`` clients of which a
     cohort of ``--cohort`` participates per round (``--cohort-bias``
     selects the sampling weights). The round engine runs at cohort size —
@@ -153,10 +169,11 @@ def run_cohort(args) -> dict:
             if mesh is not None else None)
     log = MetricLogger(args.out_dir, "blade_cohort")
     run_key = jax.random.fold_in(key, 2)
-    t0 = time.time()
+    t0 = time.perf_counter()
     store, hist, ledger = rounds.run_blade_fl_cohort(
         mlp_loss, spec, params, src.cohort_batch, run_key, blade.K, cohort,
         mesh=mesh, plan=plan)
+    wall_s = time.perf_counter() - t0    # the store is host-resident
     # final eval: aggregate the LAST round's cohort (the freshest models)
     from repro.core.aggregation import aggregate_once
     final = aggregate_once(store.gather(hist[-1]["cohort"]))
@@ -174,16 +191,15 @@ def run_cohort(args) -> dict:
         "chain_valid": ledger.validate_chain(), "blocks": len(ledger.blocks),
         "devices": mesh.devices.size if mesh is not None else 1,
         "dispatch": dict(rounds.LAST_DISPATCH),
-        "wall_s": time.time() - t0,
+        "wall_s": wall_s,
         # intra-cohort mixing diagnostics at size A (the enrolled graph is
         # never materialized — that is the point)
         **spectral_fields(spec, run_key, blade.K),
     }
-    print(json.dumps(result, indent=1))
-    return result
+    return RunOutput(result, spec, store, hist, ledger)
 
 
-def run_arch_smoke(args) -> dict:
+def run_arch_smoke(args) -> RunOutput:
     cfg = get_smoke_arch(args.arch)
     shape = ShapeConfig("smoke", args.seq, args.clients * args.per_client, "train")
     spec = rounds.RoundSpec(n_clients=args.clients, tau=2, eta=1e-2,
@@ -204,12 +220,14 @@ def run_arch_smoke(args) -> dict:
 
     mesh = make_client_mesh(args.devices) if args.devices else None
     run_key = jax.random.fold_in(key, 2)
-    t0 = time.time()
+    batches = src.stacked_batches(args.rounds)
+    t0 = time.perf_counter()
     # stacked [K, C, ...] token streams -> compiled scan engine;
     # --devices shards the client axis over the mesh, same as the mlp path
     state, hist, ledger = rounds.run_blade_fl(
-        loss_fn, spec, params, src.stacked_batches(args.rounds),
-        run_key, args.rounds, stacked=True, mesh=mesh)
+        loss_fn, spec, params, batches, run_key, args.rounds, stacked=True,
+        mesh=mesh)
+    wall_s = _blocked_seconds(state, t0)
     result = {
         "arch": cfg.name, "rounds": args.rounds,
         "loss_curve": [h["global_loss"] for h in hist],
@@ -217,14 +235,21 @@ def run_arch_smoke(args) -> dict:
         "devices": mesh.devices.size if mesh is not None else 1,
         "fast_allreduce": spec.fast_allreduce,
         "dispatch": dict(rounds.LAST_DISPATCH),
-        "wall_s": time.time() - t0,
+        "wall_s": wall_s,
         **spectral_fields(spec, run_key, args.rounds),
     }
-    print(json.dumps(result, indent=1))
-    return result
+    return RunOutput(result, spec, state, hist, ledger, batches)
 
 
-def main():
+def _blocked_seconds(state, t0: float) -> float:
+    """Seconds since ``t0`` once the final carry is on the device (the
+    engine returns before the device finishes)."""
+    jax.block_until_ready(state)
+    return time.perf_counter() - t0
+
+
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
+    """Parse and cross-check the CLI (``argv=None`` reads ``sys.argv``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mlp")
     ap.add_argument("--smoke", action="store_true")
@@ -306,7 +331,7 @@ def main():
                          "cluster:<g> so the mix is the in-cluster mean + "
                          "cluster-ring exchange")
     ap.add_argument("--out-dir", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.schedule:
         args.topology = args.schedule
     if args.clusters:
@@ -314,14 +339,23 @@ def main():
             ap.error("--clusters hierarchical mode runs the mlp substrate")
         if args.topology == "full" and not args.schedule:
             args.topology = f"cluster:{args.clusters}"
+    if args.enrolled > 0 and args.arch != "mlp":
+        ap.error("--enrolled cohort mode runs the mlp substrate")
+    return args
+
+
+def run(args: argparse.Namespace) -> RunOutput:
+    """Dispatch parsed arguments to the run path they select."""
     if args.enrolled > 0:
-        if args.arch != "mlp":
-            ap.error("--enrolled cohort mode runs the mlp substrate")
-        run_cohort(args)
-    elif args.arch == "mlp":
-        run_mlp(args)
-    else:
-        run_arch_smoke(args)
+        return run_cohort(args)
+    if args.arch == "mlp":
+        return run_mlp(args)
+    return run_arch_smoke(args)
+
+
+def main(argv: Optional[List[str]] = None):
+    enable_compile_cache()
+    print(json.dumps(run(parse_args(argv)).result, indent=1))
 
 
 if __name__ == "__main__":

@@ -19,23 +19,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def make_fake_mesh(shape=(16, 16), axes=("data", "model")):
-    """Abstract mesh for spec construction (no real devices needed).
-
-    Version-compat shim: JAX 0.4.37 wants ``AbstractMesh(shape_tuple)`` with
-    a tuple of ``(name, size)`` pairs; older/newer releases took
-    ``(shape, axes)`` or a dict. Any mesh test should use this one helper
-    instead of growing its own fallback chain.
-    """
+    """Abstract mesh for spec construction (no real devices needed)."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        pass
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(dict(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 @pytest.fixture

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import aggregation, rounds, topology
 from repro.data.pipeline import FLDataSource
@@ -193,10 +192,10 @@ def test_mix_psum_dense_variant_unsharded_is_mix():
 def test_mix_psum_sharded_close_to_all_reduce():
     p = _params(jax.random.key(2))
     mesh = _one_device_mesh()
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         lambda q: aggregation.mix_psum(q, axis_name="data", n_shards=1),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-        check_rep=False))(p)
+        check_vma=False))(p)
     assert_trees_close(got, aggregation.mix_all_reduce(p), rtol=1e-6,
                        atol=1e-7)
 
@@ -207,11 +206,11 @@ def test_mix_psum_dense_sharded_close_to_mix_gather():
     w = topology.LinkQualitySchedule(fading_period=2).matrix(
         8, round_idx=jnp.int32(1))
     weights = jnp.arange(1.0, 9.0)
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         lambda q: aggregation.mix_psum_dense(q, w, weights, axis_name="data",
                                              n_shards=1),
         mesh=_one_device_mesh(), in_specs=P("data"), out_specs=P("data"),
-        check_rep=False))(p)
+        check_vma=False))(p)
     assert_trees_close(got, aggregation.mix(p, w, weights), rtol=1e-6,
                        atol=1e-7)
 

@@ -118,7 +118,6 @@ def test_multi_axis_halo_and_cluster_grid_subprocess():
         import json
         import jax, numpy as np, jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import aggregation
 
         C = 16
@@ -132,8 +131,8 @@ def test_multi_axis_halo_and_cluster_grid_subprocess():
         }
 
         def sharded(fn):
-            wrapped = shard_map(fn, mesh=mesh, in_specs=P(axes),
-                                out_specs=P(axes), check_rep=False)
+            wrapped = jax.shard_map(fn, mesh=mesh, in_specs=P(axes),
+                                    out_specs=P(axes), check_vma=False)
             return jax.jit(wrapped)
 
         def bitwise(a, b):

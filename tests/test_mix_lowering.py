@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import aggregation, topology
@@ -135,8 +134,8 @@ def test_sharded_mix_bitwise_equals_dense(topo):
         return aggregation.mix_gather(params, w, axis_name="data", n_shards=1)
 
     want = jax.jit(dense)(p)
-    got = jax.jit(shard_map(sharded, mesh=mesh, in_specs=P("data"),
-                            out_specs=P("data"), check_rep=False))(p)
+    got = jax.jit(jax.shard_map(sharded, mesh=mesh, in_specs=P("data"),
+                                out_specs=P("data"), check_vma=False))(p)
     for k in p:
         np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
 
@@ -150,10 +149,10 @@ def test_mix_shift_halo_matches_rolls_bitwise(shift):
     offsets = (0, shift)
     mesh = _one_device_mesh()
     want = jax.jit(lambda q: aggregation.mix_rolls(q, offsets, 0.5))(p)
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         lambda q: aggregation.mix_shift_halo(q, offsets, 0.5, "data"),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-        check_rep=False))(p)
+        check_vma=False))(p)
     for k in p:
         np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
 
@@ -195,8 +194,8 @@ def test_sharded_schedule_mix_bitwise_equals_dense(sched):
             want = aggregation.mix(p, w)
             sharded = lambda q: aggregation.mix_gather(  # noqa: E731
                 q, w, axis_name="data", n_shards=1)
-        got = jax.jit(shard_map(sharded, mesh=mesh, in_specs=P("data"),
-                                out_specs=P("data"), check_rep=False))(p)
+        got = jax.jit(jax.shard_map(sharded, mesh=mesh, in_specs=P("data"),
+                                    out_specs=P("data"), check_vma=False))(p)
         for k in p:
             np.testing.assert_array_equal(np.asarray(got[k]),
                                           np.asarray(want[k]))
@@ -211,8 +210,8 @@ def test_client_gather_slice_roundtrip_under_shard_map():
         full = aggregation.client_all_gather(params, "data")
         return aggregation.client_local_rows(full, "data", n_shards=1)
 
-    got = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
-                            out_specs=P("data"), check_rep=False))(p)
+    got = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                                out_specs=P("data"), check_vma=False))(p)
     for k in p:
         np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(p[k]))
 

@@ -172,7 +172,6 @@ def test_shift_halo_rolls_and_dense_mix_agree(c, shift, seed):
     (`mix_shift_halo` under shard_map) is BITWISE the dense roll form
     (`mix_rolls`), and both match the dense matrix mix (`aggregation.mix`
     with PairShift(s).matrix) to float tolerance (matmul reassociates)."""
-    import jax.experimental.shard_map as shard_map_lib
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import topology
 
@@ -181,10 +180,10 @@ def test_shift_halo_rolls_and_dense_mix_agree(c, shift, seed):
     offsets = (0, shift)
     rolls = aggregation.mix_rolls(p, offsets, 0.5)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    halo = jax.jit(shard_map_lib.shard_map(
+    halo = jax.jit(jax.shard_map(
         lambda q: aggregation.mix_shift_halo(q, offsets, 0.5, "data"),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-        check_rep=False))(p)
+        check_vma=False))(p)
     np.testing.assert_array_equal(np.asarray(halo["w"]),
                                   np.asarray(rolls["w"]))
     dense = aggregation.mix(p, topology.PairShift(shift=shift).matrix(c))

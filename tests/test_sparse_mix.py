@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import aggregation, rounds, topology
 from repro.models.mlp import init_mlp, mlp_loss
@@ -352,11 +351,11 @@ def test_mix_segment_sharded_bitwise():
     idx, w = jnp.asarray(sp.neighbor_idx), jnp.asarray(sp.edge_w)
     want = aggregation.mix_segment(params, idx, w)
     mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p: aggregation.mix_segment(p, idx, w, axis_name="data",
                                           n_shards=4),
         mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
-        check_rep=False)
+        check_vma=False)
     got = fn(params)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
