@@ -8,5 +8,6 @@ from repro.core import (  # noqa: F401
     lazy,
     mining,
     rounds,
+    telemetry,
     topology,
 )

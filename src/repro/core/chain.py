@@ -13,6 +13,8 @@ import hashlib
 import struct
 from typing import List, Optional
 
+from repro.core import telemetry
+
 
 def sha_u32(*words: int) -> int:
     """uint32 digest via sha256 over packed words (ledger-level hash)."""
@@ -65,12 +67,13 @@ class Ledger:
         return True
 
     def validate_chain(self) -> bool:
-        prev = GENESIS_HASH
-        for i, b in enumerate(self.blocks):
-            if not self.validate_block(b, prev, i):
-                return False
-            prev = b.header_hash
-        return True
+        with telemetry.span("ledger.validate", blocks=len(self.blocks)):
+            prev = GENESIS_HASH
+            for i, b in enumerate(self.blocks):
+                if not self.validate_block(b, prev, i):
+                    return False
+                prev = b.header_hash
+            return True
 
     def tampered_copy(self, index: int, **changes) -> "Ledger":
         """Return a copy with block ``index`` altered (for tamper tests)."""
@@ -101,12 +104,14 @@ def ledger_from_scan(digests, winners, nonces, pow_hashes,
     """
     ledger = ledger if ledger is not None else Ledger()
     start = len(ledger.blocks)
-    for i in range(len(digests)):
-        block = make_block(
-            index=start + i, prev_hash=ledger.head_hash,
-            model_digest=int(digests[i]), winner=int(winners[i]),
-            nonce=int(nonces[i]), pow_hash=int(pow_hashes[i]))
-        ledger.append(block)
-    if not ledger.validate_chain():
-        raise ValueError("scan-reconstructed ledger failed chain validation")
+    with telemetry.span("ledger", blocks=len(digests)):
+        for i in range(len(digests)):
+            block = make_block(
+                index=start + i, prev_hash=ledger.head_hash,
+                model_digest=int(digests[i]), winner=int(winners[i]),
+                nonce=int(nonces[i]), pow_hash=int(pow_hashes[i]))
+            ledger.append(block)
+        if not ledger.validate_chain():
+            raise ValueError(
+                "scan-reconstructed ledger failed chain validation")
     return ledger

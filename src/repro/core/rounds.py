@@ -54,6 +54,12 @@ testable:
 ``make_integrated_round`` is now just the composition of those stages — add
 a scenario by swapping a stage, not by editing a 70-line closure.
 
+Each stage function runs under ``jax.named_scope`` of its stage, and the
+drivers open ``blade.*`` host spans around their phases (plan, init,
+dispatch, fetch, history, ledger; cohort, data and store per cohort round),
+so a profiler trace splits device time by stage and idle time by host
+phase (``core/telemetry.py``).
+
 The communication pattern of Steps 2+5 is pluggable via
 ``RoundSpec.topology`` (``core/topology.py``): a ``Topology`` yields a
 row-stochastic mixing matrix ``W [C, C]`` per round and the communicate
@@ -119,7 +125,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import (aggregation, attacks as attacks_lib, chain,
                         detection, dp as dp_lib, lazy as lazy_lib, mining,
-                        topology as topology_lib)
+                        telemetry, topology as topology_lib)
 from repro.sharding import plans as plans_lib
 
 LossFn = Callable[[Any, Any], Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]]
@@ -310,6 +316,7 @@ def make_local_train(loss_fn: LossFn, spec: RoundSpec, n_shards: int = 1):
     per_client_grad = jax.vmap(grad_fn)
     n_local = spec.n_clients // n_shards
 
+    @telemetry.stage("local_train")
     def local_train(params, batch):
         def local_iter(_, carry):
             p, _ = carry
@@ -351,6 +358,7 @@ def make_perturb(spec: RoundSpec, axis_name=None, n_shards: int = 1):
     re-gathering the model it just materialized."""
     active = spec.n_lazy > 0 or spec.dp_sigma > 0.0
 
+    @telemetry.stage("perturb")
     def perturb(params, k_lazy, k_dp):
         if not active:
             return params, None
@@ -389,6 +397,7 @@ def make_attack(spec: RoundSpec, axis_name=None, n_shards: int = 1):
     if active:
         atk._validate(spec.n_clients)   # fail at build time, not in-trace
 
+    @telemetry.stage("attack")
     def attack(params, k_dp, full=None):
         if not active:
             return params, full
@@ -517,6 +526,7 @@ def make_communicate(spec: RoundSpec, axis_name=None, n_shards: int = 1,
                 p, o, plan.weight, axis_name) for o in plan.offsets_table],
             params)
 
+    @telemetry.stage("communicate")
     def communicate(params, prev_params, k_topo, round_idx, full=None):
         if plan.fast_diagnostics:
             # tolerance tier: psum'd diagnostics + mix, no broadcast gather.
@@ -641,6 +651,7 @@ def make_mine(spec: RoundSpec, axis_name=None, n_shards: int = 1):
     if spec.use_kernel:
         from repro.kernels.pow_hash import ops as pow_ops
 
+    @telemetry.stage("mine")
     def mine(prev_hash, digest, round_idx):
         client_ids = jnp.arange(n_local, dtype=jnp.uint32)
         if axis_name is not None:
@@ -712,6 +723,7 @@ def make_finalize(loss_fn: LossFn, spec: RoundSpec, axis_name=None,
         glosses = jax.vmap(lambda p, b: loss_fn(p, b)[0])(params, batch)
         return aggregation.client_all_gather(glosses, axis_name)
 
+    @telemetry.stage("finalize")
     def finalize(state, params, key, new_hash, batch, metrics):
         if spec.eval_global_loss:
             if spec.eval_every <= 1:
@@ -926,6 +938,77 @@ def _round_runner(loss_fn: LossFn, spec: RoundSpec,
     return jax.jit(make_integrated_round(loss_fn, spec, n_rounds=n_rounds))
 
 
+def _run_counts(spec: RoundSpec, n_rounds: int) -> Dict[str, int]:
+    """The counts a ``blade.run`` span carries: rounds, clients per round,
+    and PoW hashes attempted over the call."""
+    n_rounds = int(n_rounds)
+    return {"rounds": n_rounds, "clients": spec.n_clients,
+            "hashes": n_rounds * spec.n_clients * spec.mine_attempts}
+
+
+def _history_entry(metrics) -> Dict[str, float]:
+    """One round's history row from its metrics on the host: scalars as
+    floats, the per-client ``[C]`` losses reduced here with ``np.mean``
+    (see :func:`make_finalize` for why not on the device)."""
+    metrics = dict(metrics)
+    glosses = metrics.pop("global_loss", None)
+    llosses = metrics.pop("local_loss")
+    entry = {name: float(v) for name, v in metrics.items()}
+    entry["local_loss_mean"] = float(np.mean(llosses))
+    if glosses is not None:
+        entry["global_loss"] = float(np.mean(glosses))
+    return entry
+
+
+def _append_block(ledger: chain.Ledger, metrics) -> None:
+    """Seal one round's host metrics into the next block of ``ledger``."""
+    ledger.append(chain.make_block(
+        index=len(ledger.blocks), prev_hash=ledger.head_hash,
+        model_digest=int(metrics["digest"]), winner=int(metrics["winner"]),
+        nonce=int(metrics["nonce"]), pow_hash=int(metrics["pow_hash"])))
+
+
+def _scan_setup(loss_fn: LossFn, spec: RoundSpec, batch, n_rounds: int,
+                stacked: bool, mesh: Optional[Mesh],
+                plan: Optional["plans_lib.ScanCarryPlan"]):
+    """Check the static batch and look up the cached K-round runner."""
+    if callable(batch):
+        raise TypeError(
+            "run_blade_fl_scan needs a static batch pytree; use "
+            "run_blade_fl for per-round batch callables")
+    if stacked:
+        leads = {x.shape[0] for x in jax.tree.leaves(batch)}
+        if leads != {int(n_rounds)}:
+            raise ValueError(
+                f"stacked batch leading dims {sorted(leads)} != "
+                f"n_rounds={int(n_rounds)}; scan takes its length from xs")
+    if mesh is not None and plan is None:
+        plan = plans_lib.scan_carry_plan(mesh, spec.n_clients)
+    return _scan_runner(loss_fn, spec, int(n_rounds), bool(stacked), mesh,
+                        plan)
+
+
+def _scan_call(runner, spec: RoundSpec, params_single, batch, key,
+               n_rounds: int, ledger: Optional[chain.Ledger]):
+    """One call of a K-round runner: the carry, the jitted call, the one
+    host transfer, the history and the replayed ledger."""
+    with telemetry.span("init"):
+        # the runner donates the carry: copy the caller's key into it so
+        # the caller's own key stays alive
+        state = init_state(params_single, key.copy(), spec.n_clients)
+    with telemetry.span("dispatch"):
+        state, stacked_metrics = runner(state, batch)
+    with telemetry.span("fetch", bytes=telemetry.nbytes(stacked_metrics)):
+        host = jax.device_get(stacked_metrics)   # the one host transfer
+    with telemetry.span("history"):
+        history = [_history_entry({name: v[k] for name, v in host.items()})
+                   for k in range(int(n_rounds))]
+    ledger = chain.ledger_from_scan(
+        host["digest"], host["winner"], host["nonce"], host["pow_hash"],
+        ledger=ledger)
+    return state, history, ledger
+
+
 def run_blade_fl_scan(loss_fn: LossFn, spec: RoundSpec, params_single, batch,
                       key, n_rounds: int,
                       ledger: Optional[chain.Ledger] = None,
@@ -948,40 +1031,12 @@ def run_blade_fl_scan(loss_fn: LossFn, spec: RoundSpec, params_single, batch,
     params, metrics, ledger hash links — are bit-for-bit those of the
     single-device scan (see module docstring).
     """
-    if callable(batch):
-        raise TypeError(
-            "run_blade_fl_scan needs a static batch pytree; use "
-            "run_blade_fl for per-round batch callables")
-    if stacked:
-        leads = {x.shape[0] for x in jax.tree.leaves(batch)}
-        if leads != {int(n_rounds)}:
-            raise ValueError(
-                f"stacked batch leading dims {sorted(leads)} != "
-                f"n_rounds={int(n_rounds)}; scan takes its length from xs")
-    if mesh is not None and plan is None:
-        plan = plans_lib.scan_carry_plan(mesh, spec.n_clients)
-    runner = _scan_runner(loss_fn, spec, int(n_rounds), bool(stacked),
-                          mesh, plan)
-    # the runner donates the carry: copy the caller's key into it so the
-    # caller's own key stays alive
-    state = init_state(params_single, key.copy(), spec.n_clients)
-    state, stacked_metrics = runner(state, batch)
-    host = jax.device_get(stacked_metrics)   # the one host transfer
-    # the engine emits per-client losses [K, C]; the scalar means are
-    # reduced here on host (see make_finalize / make_integrated_round)
-    glosses = host.pop("global_loss", None)
-    llosses = host.pop("local_loss")
-    history = [{name: float(v[k]) for name, v in host.items()}
-               for k in range(int(n_rounds))]
-    for k in range(int(n_rounds)):
-        history[k]["local_loss_mean"] = float(np.mean(llosses[k]))
-    if glosses is not None:
-        for k in range(int(n_rounds)):
-            history[k]["global_loss"] = float(np.mean(glosses[k]))
-    ledger = chain.ledger_from_scan(
-        host["digest"], host["winner"], host["nonce"], host["pow_hash"],
-        ledger=ledger)
-    return state, history, ledger
+    with telemetry.span("run", **_run_counts(spec, n_rounds)):
+        with telemetry.span("plan"):
+            runner = _scan_setup(loss_fn, spec, batch, n_rounds, stacked,
+                                 mesh, plan)
+        return _scan_call(runner, spec, params_single, batch, key, n_rounds,
+                          ledger)
 
 
 def run_blade_fl(loss_fn: LossFn, spec: RoundSpec, params_single, batches,
@@ -1001,53 +1056,54 @@ def run_blade_fl(loss_fn: LossFn, spec: RoundSpec, params_single, batches,
     :data:`LAST_DISPATCH`. ``mesh`` (+ optional ``plan``) selects the
     client-sharded scan engine and therefore requires the static-batch path.
     """
-    decision = dispatch_plan(spec, batches, n_rounds, jit=jit,
-                             stacked=stacked, mesh=mesh)
-    LAST_DISPATCH.clear()
-    LAST_DISPATCH.update(decision)
-    if spec.use_kernel and decision["pow"] == "fori_loop":
-        spec = dataclasses.replace(spec, use_kernel=False)
-    if decision["driver"] == "scan":
-        return run_blade_fl_scan(loss_fn, spec, params_single, batches, key,
-                                 n_rounds, ledger=ledger, stacked=stacked,
-                                 mesh=mesh, plan=plan)
-    if mesh is not None:
-        raise ValueError(
-            "mesh-sharded execution needs the compiled scan engine: pass a "
-            "static batch pytree and jit=True (per-round batch callables "
-            "would reshard the carry every round)")
-    # the horizon only matters to the forced last-round eval; keep it out of
-    # the runner cache key when eval_every == 1 so K-sweeps share one
-    # compiled round
-    horizon = int(n_rounds) if spec.eval_every > 1 else None
-    round_fn = _round_runner(loss_fn, spec, horizon) if jit \
-        else make_integrated_round(loss_fn, spec, n_rounds=horizon)
-    state = init_state(params_single, key, spec.n_clients)
-    ledger = ledger if ledger is not None else chain.Ledger()
-    history = []
-    for k in range(n_rounds):
-        if callable(batches):
-            batch = batches(k)
-        elif stacked:
-            batch = jax.tree.map(lambda x: x[k], batches)
-        else:
-            batch = batches
-        state, metrics = round_fn(state, batch)
-        block = chain.make_block(
-            index=len(ledger.blocks), prev_hash=ledger.head_hash,
-            model_digest=int(metrics["digest"]), winner=int(metrics["winner"]),
-            nonce=int(metrics["nonce"]), pow_hash=int(metrics["pow_hash"]))
-        ledger.append(block)
-        metrics = dict(metrics)
-        glosses = metrics.pop("global_loss", None)
-        llosses = metrics.pop("local_loss")
-        entry = {k2: float(v) for k2, v in metrics.items()}
-        # identical host-side reductions to the scan driver's
-        entry["local_loss_mean"] = float(np.mean(np.asarray(llosses)))
-        if glosses is not None:
-            entry["global_loss"] = float(np.mean(np.asarray(glosses)))
-        history.append(entry)
-    return state, history, ledger
+    with telemetry.span("run", **_run_counts(spec, n_rounds)):
+        with telemetry.span("plan"):
+            decision = dispatch_plan(spec, batches, n_rounds, jit=jit,
+                                     stacked=stacked, mesh=mesh)
+            LAST_DISPATCH.clear()
+            LAST_DISPATCH.update(decision)
+            if spec.use_kernel and decision["pow"] == "fori_loop":
+                spec = dataclasses.replace(spec, use_kernel=False)
+            if decision["driver"] == "scan":
+                runner = _scan_setup(loss_fn, spec, batches, n_rounds,
+                                     stacked, mesh, plan)
+            elif mesh is not None:
+                raise ValueError(
+                    "mesh-sharded execution needs the compiled scan engine: "
+                    "pass a static batch pytree and jit=True (per-round "
+                    "batch callables would reshard the carry every round)")
+            else:
+                # the horizon only matters to the forced last-round eval;
+                # keep it out of the runner cache key when eval_every == 1
+                # so K-sweeps share one compiled round
+                horizon = int(n_rounds) if spec.eval_every > 1 else None
+                round_fn = (_round_runner(loss_fn, spec, horizon) if jit
+                            else make_integrated_round(loss_fn, spec,
+                                                       n_rounds=horizon))
+        if decision["driver"] == "scan":
+            return _scan_call(runner, spec, params_single, batches, key,
+                              n_rounds, ledger)
+        with telemetry.span("init"):
+            state = init_state(params_single, key, spec.n_clients)
+        ledger = ledger if ledger is not None else chain.Ledger()
+        history = []
+        for k in range(n_rounds):
+            if callable(batches):
+                batch = batches(k)
+            elif stacked:
+                batch = jax.tree.map(lambda x: x[k], batches)
+            else:
+                batch = batches
+            with telemetry.span("dispatch", round=k):
+                state, metrics = round_fn(state, batch)
+            with telemetry.span("fetch", round=k,
+                                bytes=telemetry.nbytes(metrics)):
+                metrics = jax.device_get(metrics)
+            with telemetry.span("ledger", round=k):
+                _append_block(ledger, metrics)
+            with telemetry.span("history", round=k):
+                history.append(_history_entry(metrics))
+        return state, history, ledger
 
 
 # ---------------------------------------------------------------------------
@@ -1067,10 +1123,11 @@ class PopulationStore:
     10k-population run that ever activates 400 distinct clients stores 401
     model copies.
 
-    ``gather(idx)`` stacks the cohort's rows into device arrays;
-    ``scatter(idx, cohort_params)`` writes a round's post-mix cohort back
-    (one ``device_get``, rows copied out so no stacked device buffer is
-    pinned).
+    ``gather(idx)`` stacks the cohort's rows into device arrays (span
+    ``blade.store.gather``); ``scatter(idx, cohort_params)`` writes a
+    round's post-mix cohort back: one ``device_get`` (span ``blade.fetch``),
+    then the rows copied out so no stacked device buffer is pinned (span
+    ``blade.store.scatter``).
     """
 
     def __init__(self, params_single, n_enrolled: int):
@@ -1078,6 +1135,7 @@ class PopulationStore:
             raise ValueError("PopulationStore needs n_enrolled >= 1")
         self.n_enrolled = int(n_enrolled)
         self._init = jax.tree.map(lambda x: np.asarray(x), params_single)
+        self._row_bytes = telemetry.nbytes(self._init)
         self._rows: Dict[int, Any] = {}
 
     @property
@@ -1087,8 +1145,7 @@ class PopulationStore:
 
     def materialized_bytes(self) -> int:
         """Host bytes held beyond the shared init model."""
-        row_bytes = sum(x.nbytes for x in jax.tree.leaves(self._init))
-        return row_bytes * self.touched
+        return self._row_bytes * self.touched
 
     def _check_idx(self, idx: np.ndarray) -> np.ndarray:
         idx = np.asarray(idx)
@@ -1103,20 +1160,27 @@ class PopulationStore:
     def gather(self, idx) -> Any:
         """Stack rows ``idx`` into a ``[len(idx), ...]`` device pytree."""
         idx = self._check_idx(idx)
-        rows = [self._rows.get(int(i), self._init) for i in idx]
-        return jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *rows)
+        with telemetry.span("store.gather", rows=idx.size,
+                            bytes=idx.size * self._row_bytes):
+            rows = [self._rows.get(int(i), self._init) for i in idx]
+            return jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *rows)
 
     def scatter(self, idx, cohort_params) -> None:
         """Write a round's post-mix ``[len(idx), ...]`` cohort stack back."""
         idx = self._check_idx(idx)
-        host = jax.device_get(cohort_params)
+        with telemetry.span("fetch", bytes=telemetry.nbytes(cohort_params)):
+            host = jax.device_get(cohort_params)
         leads = {x.shape[0] for x in jax.tree.leaves(host)}
         if leads != {idx.size}:
             raise ValueError(
                 f"cohort_params leading dims {sorted(leads)} != "
                 f"len(idx)={idx.size}")
-        for a, i in enumerate(idx):
-            self._rows[int(i)] = jax.tree.map(lambda x: np.array(x[a]), host)
+        with telemetry.span("store.scatter", rows=idx.size) as span:
+            before = self.touched
+            for a, i in enumerate(idx):
+                self._rows[int(i)] = jax.tree.map(lambda x: np.array(x[a]),
+                                                  host)
+            span.set_metadata(new_rows=self.touched - before)
 
 
 @functools.lru_cache(maxsize=16)
@@ -1208,49 +1272,54 @@ def run_blade_fl_cohort(loss_fn: LossFn, spec: RoundSpec, params_single,
         host_batches = jax.tree.map(np.asarray, batches)
 
         def batch_fn(k, idx):
-            return jax.tree.map(lambda x: jnp.asarray(x[np.asarray(idx)]),
-                                host_batches)
+            with telemetry.span("data", round=k):
+                return jax.tree.map(
+                    lambda x: jnp.asarray(x[np.asarray(idx)]), host_batches)
 
-    if mesh is not None and plan is None:
-        plan = plans_lib.cohort_carry_plan(mesh, cohort.n_enrolled,
-                                           spec.n_clients)
-    decision = dispatch_plan(spec, batches, n_rounds, mesh=mesh)
-    decision.update(driver="cohort",
-                    reason=f"cohort A={cohort.cohort_size} over "
-                           f"C_enrolled={cohort.n_enrolled}")
-    LAST_DISPATCH.clear()
-    LAST_DISPATCH.update(decision)
-    # mirror run_blade_fl's horizon handling so A == C_enrolled cohort runs
-    # reuse (and bitwise match) the loop driver's cached runner
-    horizon = int(n_rounds) if spec.eval_every > 1 else None
-    runner = _cohort_round_runner(loss_fn, spec, horizon, mesh, plan)
-    ledger = ledger if ledger is not None else chain.Ledger()
-    history = []
-    host_key = key
-    prev_hash = jnp.uint32(chain.GENESIS_HASH)
-    for k in range(int(n_rounds)):
-        # host mirror of the round body's split chain (= topology_keys)
-        next_key, _k_lazy, k_dp = jax.random.split(host_key, 3)
-        k_topo = jax.random.fold_in(k_dp, _TOPOLOGY_SALT)
-        idx = np.asarray(cohort.cohort_at(k_topo))
-        state = RoundState(params=store.gather(idx), key=host_key,
-                           round_idx=jnp.int32(k), prev_hash=prev_hash)
-        state, metrics = runner(state, batch_fn(k, idx))
-        store.scatter(idx, state.params)
-        prev_hash = state.prev_hash
-        host_key = next_key
-        block = chain.make_block(
-            index=len(ledger.blocks), prev_hash=ledger.head_hash,
-            model_digest=int(metrics["digest"]), winner=int(metrics["winner"]),
-            nonce=int(metrics["nonce"]), pow_hash=int(metrics["pow_hash"]))
-        ledger.append(block)
-        metrics = dict(metrics)
-        glosses = metrics.pop("global_loss", None)
-        llosses = metrics.pop("local_loss")
-        entry = {k2: float(v) for k2, v in metrics.items()}
-        entry["local_loss_mean"] = float(np.mean(np.asarray(llosses)))
-        if glosses is not None:
-            entry["global_loss"] = float(np.mean(np.asarray(glosses)))
-        entry["cohort"] = [int(i) for i in idx]
-        history.append(entry)
-    return store, history, ledger
+    with telemetry.span("run", **_run_counts(spec, n_rounds)):
+        with telemetry.span("plan"):
+            if mesh is not None and plan is None:
+                plan = plans_lib.cohort_carry_plan(mesh, cohort.n_enrolled,
+                                                   spec.n_clients)
+            decision = dispatch_plan(spec, batches, n_rounds, mesh=mesh)
+            decision.update(driver="cohort",
+                            reason=f"cohort A={cohort.cohort_size} over "
+                                   f"C_enrolled={cohort.n_enrolled}")
+            LAST_DISPATCH.clear()
+            LAST_DISPATCH.update(decision)
+            # mirror run_blade_fl's horizon handling so A == C_enrolled
+            # cohort runs reuse (and bitwise match) the loop driver's cached
+            # runner
+            horizon = int(n_rounds) if spec.eval_every > 1 else None
+            runner = _cohort_round_runner(loss_fn, spec, horizon, mesh, plan)
+        ledger = ledger if ledger is not None else chain.Ledger()
+        history = []
+        host_key = key
+        prev_hash = jnp.uint32(chain.GENESIS_HASH)
+        for k in range(int(n_rounds)):
+            with telemetry.span("cohort", round=k):
+                # host mirror of the round body's split chain
+                # (= topology_keys)
+                next_key, _k_lazy, k_dp = jax.random.split(host_key, 3)
+                k_topo = jax.random.fold_in(k_dp, _TOPOLOGY_SALT)
+                idx = np.asarray(cohort.cohort_at(k_topo))
+            batch = batch_fn(k, idx)
+            params = store.gather(idx)
+            with telemetry.span("dispatch", round=k):
+                state = RoundState(params=params, key=host_key,
+                                   round_idx=jnp.int32(k),
+                                   prev_hash=prev_hash)
+                state, metrics = runner(state, batch)
+            store.scatter(idx, state.params)
+            prev_hash = state.prev_hash
+            host_key = next_key
+            with telemetry.span("fetch", round=k,
+                                bytes=telemetry.nbytes(metrics)):
+                metrics = jax.device_get(metrics)
+            with telemetry.span("ledger", round=k):
+                _append_block(ledger, metrics)
+            with telemetry.span("history", round=k):
+                entry = _history_entry(metrics)
+                entry["cohort"] = [int(i) for i in idx]
+                history.append(entry)
+        return store, history, ledger

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.core import telemetry
 from repro.data import synthetic
 
 
@@ -86,6 +87,7 @@ class CohortDataSource:
         self.eval_data = self._draw(k_eval, 2048, skew=False)
         self._cache: Dict[int, Dict[str, jnp.ndarray]] = {}
         self._cache_size = cache_size
+        self.draws = 0      # client datasets drawn (cache misses)
 
     def _draw(self, key, n: int, skew: bool = True) -> Dict[str, jnp.ndarray]:
         k_prop, k_lbl, k_noise = jax.random.split(key, 3)
@@ -108,8 +110,10 @@ class CohortDataSource:
         hit = self._cache.get(cid)
         if hit is not None:
             return hit
-        batch = self._draw(jax.random.fold_in(self._client_key, cid),
-                           self.samples_per_client)
+        with telemetry.span("data.draw", client=cid):
+            batch = self._draw(jax.random.fold_in(self._client_key, cid),
+                               self.samples_per_client)
+        self.draws += 1
         if len(self._cache) >= self._cache_size:
             self._cache.pop(next(iter(self._cache)))
         self._cache[cid] = batch
@@ -118,9 +122,15 @@ class CohortDataSource:
     def cohort_batch(self, round_idx: int, cohort_idx) -> Dict[str, jnp.ndarray]:
         """The ``[A, m, ...]`` stack for a round's cohort (full-batch GD:
         round_idx is unused, each client always trains its fixed local
-        set)."""
-        rows = [self.client_batch(i) for i in np.asarray(cohort_idx)]
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
+        set). Span ``blade.data``, counting the datasets ``draws`` (cache
+        misses) and ``hits``."""
+        ids = np.asarray(cohort_idx)
+        with telemetry.span("data", round=int(round_idx)) as span:
+            before = self.draws
+            rows = [self.client_batch(i) for i in ids]
+            span.set_metadata(draws=self.draws - before,
+                              hits=ids.size - (self.draws - before))
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
 
 
 class LMDataSource:

@@ -25,6 +25,7 @@ docs/architecture.md):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 from typing import Any, List, NamedTuple, Optional
@@ -115,10 +116,11 @@ def run_mlp(args) -> RunOutput:
     t0 = time.perf_counter()
     # static batch -> compiled scan engine (K rounds, one dispatch);
     # --devices shards the client axis of the whole scan over the mesh
-    state, hist, ledger = rounds.run_blade_fl(
-        mlp_loss, spec, params, batch, run_key, blade.K, mesh=mesh,
-        plan=plan)
-    wall_s = _blocked_seconds(state, t0)
+    with _profiled(args):
+        state, hist, ledger = rounds.run_blade_fl(
+            mlp_loss, spec, params, batch, run_key, blade.K, mesh=mesh,
+            plan=plan)
+        wall_s = _blocked_seconds(state, t0)
     # final eval on held-out data with the aggregated model
     from repro.core.aggregation import aggregate_once
     final = aggregate_once(state.params)
@@ -170,9 +172,10 @@ def run_cohort(args) -> RunOutput:
     log = MetricLogger(args.out_dir, "blade_cohort")
     run_key = jax.random.fold_in(key, 2)
     t0 = time.perf_counter()
-    store, hist, ledger = rounds.run_blade_fl_cohort(
-        mlp_loss, spec, params, src.cohort_batch, run_key, blade.K, cohort,
-        mesh=mesh, plan=plan)
+    with _profiled(args):
+        store, hist, ledger = rounds.run_blade_fl_cohort(
+            mlp_loss, spec, params, src.cohort_batch, run_key, blade.K,
+            cohort, mesh=mesh, plan=plan)
     wall_s = time.perf_counter() - t0    # the store is host-resident
     # final eval: aggregate the LAST round's cohort (the freshest models)
     from repro.core.aggregation import aggregate_once
@@ -224,10 +227,11 @@ def run_arch_smoke(args) -> RunOutput:
     t0 = time.perf_counter()
     # stacked [K, C, ...] token streams -> compiled scan engine;
     # --devices shards the client axis over the mesh, same as the mlp path
-    state, hist, ledger = rounds.run_blade_fl(
-        loss_fn, spec, params, batches, run_key, args.rounds, stacked=True,
-        mesh=mesh)
-    wall_s = _blocked_seconds(state, t0)
+    with _profiled(args):
+        state, hist, ledger = rounds.run_blade_fl(
+            loss_fn, spec, params, batches, run_key, args.rounds,
+            stacked=True, mesh=mesh)
+        wall_s = _blocked_seconds(state, t0)
     result = {
         "arch": cfg.name, "rounds": args.rounds,
         "loss_curve": [h["global_loss"] for h in hist],
@@ -239,6 +243,16 @@ def run_arch_smoke(args) -> RunOutput:
         **spectral_fields(spec, run_key, args.rounds),
     }
     return RunOutput(result, spec, state, hist, ledger, batches)
+
+
+def _profiled(args):
+    """The profiler session ``--trace-dir`` asks for around the engine
+    call, else nothing. The engine's ``blade.*`` spans and stage scopes
+    land in its trace (``python3 bench/program_trace.py <dir>`` tabulates
+    them)."""
+    if not args.trace_dir:
+        return contextlib.nullcontext()
+    return jax.profiler.trace(args.trace_dir)
 
 
 def _blocked_seconds(state, t0: float) -> float:
@@ -331,6 +345,11 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
                          "cluster:<g> so the mix is the in-cluster mean + "
                          "cluster-ring exchange")
     ap.add_argument("--out-dir", default=None)
+    ap.add_argument("--trace-dir", default=None,
+                    help="run the engine call under jax.profiler.trace(DIR): "
+                         "host spans blade.* and device ops named by stage "
+                         "(core/telemetry.py); the first call of a process "
+                         "also traces its compile")
     args = ap.parse_args(argv)
     if args.schedule:
         args.topology = args.schedule
