@@ -132,7 +132,13 @@ def softmax_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray, mask=None):
     """logits: [..., V] (any dtype, upcast), labels int32 [...]."""
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    # Pick each label's logit with a one-hot select over the class axis, not a
+    # gather: on a TPU the gather (and its scatter transpose) costs more than
+    # the MLP's matmuls, while the select fuses with the logsumexp reduction.
+    # `where`, not a multiply by a float one-hot, so an inf elsewhere in the
+    # row cannot turn the pick into NaN.
+    hit = labels[..., None] == jnp.arange(logits.shape[-1], dtype=labels.dtype)
+    gold = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
     nll = logz - gold
     if mask is not None:
         mask = mask.astype(jnp.float32)
