@@ -18,6 +18,7 @@ import compare
 import reference
 from drivers.common import blocks_of, round_spec
 from drivers.scan_runs import control_blocks
+from families.mlp import model_dims
 from repro.core import chain, rounds, topology
 from repro.data.pipeline import CohortDataSource
 from repro.models import mlp
@@ -123,11 +124,11 @@ class Driver:
         """The reference over every call of the run: (per-call histories
         with cohorts, per-call digests, final rows of touched clients)."""
         cfg, rs = self.cfg, self.cfg["round_spec"]
-        m = cfg["model"]
+        in_dim, _, n_classes = model_dims(cfg)
         data = reference.ClientData(self.run.inputs["key"],
                                     cfg["data"]["samples_per_client"],
                                     cfg["data"]["dirichlet_alpha"],
-                                    m["in_dim"], m["n_classes"])
+                                    in_dim, n_classes)
         init = self.run.host_inputs["params"]
         store, hists, digests = {}, [], []
         for i, k, _, _ in self.calls:

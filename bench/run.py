@@ -5,9 +5,15 @@
 Everything about a cell is found by name: its entry in ``BENCHMARK.json``
 names a configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``), whose ``driver`` names the loop that
-drives the engine (``bench/drivers/<driver>.py``); each metric is read by
-``bench/metrics/<metric>.py``, and the limits that decide ``correct`` are
-in ``bench/limits/<cell>.json``.
+drives the engine (``bench/drivers/<driver>.py``); the configuration's
+``model.arch`` names its model family (``bench/families/<arch>.py``:
+``build`` makes the inputs from the seed, ``to_host`` copies them for the
+reference, ``round_flops`` counts a round's training FLOPs); each metric
+is read by ``bench/metrics/<metric>.py``, and the limits that decide
+``correct`` are in ``bench/limits/<cell>.json``. So a new model family
+comes as new files only: its family module, a driver and a reference
+where the existing ones do not serve it, its configuration, traffic and
+limits, and its entries in ``BENCHMARK.json``.
 
 A run: set-up (import and backend, inputs from the seed, warm-up of every
 shape the window uses), then the window, a closed loop of driver calls
@@ -219,7 +225,7 @@ def set_up(run, meter, t_start):
 def measure(run, driver, meter, seconds, trace):
     """The window, the memory peak, and the program's outputs brought to
     the host for the reference."""
-    import numpy as np
+    import families
     mark = meter.mark()
     run.window = window(run, driver, seconds, trace)
     run.compiles_in_window = meter.since(mark)["compiles"]
@@ -228,11 +234,7 @@ def measure(run, driver, meter, seconds, trace):
          rounds=run.window["rounds"], span_s=run.window["span_s"],
          compiles_in_window=run.compiles_in_window,
          memory_peak_bytes=run.memory_peak)
-    run.host_inputs = {"params": {n: np.asarray(v, np.float64)
-                                  for n, v in run.inputs["params"].items()}}
-    if "batch" in run.inputs:
-        run.host_inputs.update(x=run.inputs["batch"]["x"],
-                               y=run.inputs["batch"]["y"])
+    run.host_inputs = families.of(run.cfg).to_host(run.inputs)
     driver.collect()
 
 

@@ -2,7 +2,8 @@
 once for each fault a cell can have. On the CPU, at the cell's shapes."""
 import pytest
 
-from test_rehearsal import ROOT, cpu_run, result, x4_checkout
+from checkout import ROOT, cpu_run, result
+from test_rehearsal import x4_checkout
 
 FAULTS = [
     ("paper_c20_k5", 1, "state_unchanged"),

@@ -4,17 +4,14 @@ one second of window: the result line has exactly the contract's keys.
 The 4-chip cell ``paper_c20_k5_x4`` (traffic ``closed_k5_sharded4``) is
 not in ``BENCHMARK.json`` yet: it was not proven on chips. It runs here on
 four host devices from a copy of the checkout that adds its entry."""
-import json
 import os
-import shutil
 import subprocess
 import sys
 
 import pytest
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
-KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
+from checkout import KEYS, ROOT, checkout, cpu_run, limits_of, result
+
 X4 = {"name": "paper_c20_k5_x4", "config": "blade_mlp_mnist_c20",
       "traffic": "closed_k5_sharded4", "chips": 4,
       "why": "the paper round sharded 5 clients per chip over 4 chips"}
@@ -23,36 +20,8 @@ X4 = {"name": "paper_c20_k5_x4", "config": "blade_mlp_mnist_c20",
 def x4_checkout(tmp_path):
     """A copy of the checkout whose BENCHMARK.json has the 4-chip cell,
     held to the paper cell's limits."""
-    shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    os.symlink(os.path.join(ROOT, "src"), tmp_path / "src")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    bench["workloads"].append(X4)
-    for m in bench["end_to_end"]:
-        if "workloads" in m:
-            m["workloads"].append(X4["name"])
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    shutil.copy(tmp_path / "bench/limits/paper_c20_k5.json",
-                tmp_path / "bench/limits/paper_c20_k5_x4.json")
-    return tmp_path
-
-
-def cpu_run(*args, devices=1, timeout=900, root=ROOT):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    if devices > 1:
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                            f" --xla_force_host_platform_device_count={devices}")
-    proc = subprocess.run([sys.executable,
-                           os.path.join(root, "bench", "tests", "cpu_run.py"),
-                           *args], cwd=root, env=env, capture_output=True,
-                          text=True, timeout=timeout)
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    return proc
-
-
-def result(proc):
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return checkout(tmp_path, cells=[X4],
+                    limits={X4["name"]: limits_of("paper_c20_k5")})
 
 
 CELLS = [("paper_c20_k5", 1), ("cohort_10k_a64", 1), ("paper_c20_k5_x4", 4)]
